@@ -22,6 +22,8 @@ def main() -> None:
                     help="skip writing results/bench/BENCH_*.json")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (all_scan, fannkuch, find_first, moe_dispatch, recovery,
                    roofline, scan_ssm, serve_load, slo_load, sort_adaptors,
                    sort_compare, task_counts)

@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -52,9 +51,9 @@ def allgather_matmul(x: jnp.ndarray, w: jnp.ndarray, mesh: Mesh, *,
                 blk = jax.lax.ppermute(blk, axis, perm=ring)
         return y
 
-    return shard_map(spmd, mesh=mesh,
-                     in_specs=(P(axis, None), P(None, axis)),
-                     out_specs=P(None, axis), check_rep=False)(x, w)
+    return jax.shard_map(spmd, mesh=mesh,
+                         in_specs=(P(axis, None), P(None, axis)),
+                         out_specs=P(None, axis), check_vma=False)(x, w)
 
 
 def matmul_reducescatter(x: jnp.ndarray, w: jnp.ndarray, mesh: Mesh, *,
@@ -92,9 +91,9 @@ def matmul_reducescatter(x: jnp.ndarray, w: jnp.ndarray, mesh: Mesh, *,
                 + chunk((idx - k - 1) % n)
         return acc
 
-    return shard_map(spmd, mesh=mesh,
-                     in_specs=(P(None, axis), P(axis, None)),
-                     out_specs=P(axis, None), check_rep=False)(x, w)
+    return jax.shard_map(spmd, mesh=mesh,
+                         in_specs=(P(None, axis), P(axis, None)),
+                         out_specs=P(axis, None), check_vma=False)(x, w)
 
 
 __all__ = ["allgather_matmul", "matmul_reducescatter"]

@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..configs.base import ModelConfig
@@ -56,11 +55,11 @@ def moe_shard_map(params: Params, cfg: ModelConfig, x: jnp.ndarray,
         y = jnp.einsum("tf,efd,te->td", jax.nn.silu(h) * u, down_blk, seg)
         return jax.lax.psum(y, axis)
 
-    y = shard_map(
+    y = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(P(axis, None, None), P(axis, None, None),
                   P(axis, None, None), P(tok, None), P(tok)),
-        out_specs=P(tok, None), check_rep=False)(
+        out_specs=P(tok, None), check_vma=False)(
         params["gate"], params["up"], params["down"], xd, sorted_e)
 
     return sort_combine(params, cfg, x, y, sorted_tok, sorted_p), aux

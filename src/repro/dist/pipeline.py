@@ -18,7 +18,6 @@ from typing import Callable, List
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core import WorkRange, bound_depth, build_plan
@@ -113,10 +112,10 @@ def pipeline_forward(stage_fn: Callable, ws, xs, mesh: Mesh, *,
             jnp.where(idx == stages - 1, outs, jnp.zeros_like(outs)), axis)
 
     nd = xs.ndim
-    return shard_map(
+    return jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(P(axis, *([None] * (ws.ndim - 1))), P(*([None] * nd))),
-        out_specs=P(*([None] * nd)), check_rep=False)(ws, xs)
+        out_specs=P(*([None] * nd)), check_vma=False)(ws, xs)
 
 
 __all__ = ["microbatch_order", "schedule_ticks", "bubble_fraction",

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -72,8 +74,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """q: (B, Sq, H, hd)  k,v: (B, Sk, KV, hd) → (B, Sq, H, hd)."""
+    interpret = resolve_interpret(interpret)
     B, Sq, H, hd = q.shape
     _, Sk, KV, _ = k.shape
     G = H // KV

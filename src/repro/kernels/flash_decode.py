@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core import SeqWork, demand_split
+from . import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -53,9 +54,10 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, m_ref, l_ref, acc_ref, *,
 def decode_partials(q: jnp.ndarray, k_cache: jnp.ndarray,
                     v_cache: jnp.ndarray, lengths: jnp.ndarray, *,
                     block_k: int = 512, scale: Optional[float] = None,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q: (B,H,hd); caches: (B,S,KV,hd); lengths: (B,).
     Returns per-block partials (m, l, acc) with leading nk axis."""
+    interpret = resolve_interpret(interpret)
     B, H, hd = q.shape
     _, S, KV, _ = k_cache.shape
     G = H // KV
@@ -106,13 +108,14 @@ def combine_partials(part_a, part_b):
 def flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                  lengths: jnp.ndarray, *, block_k: int = 512,
                  scale: Optional[float] = None, demand: Optional[int] = None,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """Full decode attention: Pallas partials + plan-driven reduction tree.
 
     ``demand`` (default: #kv-blocks) sets the adaptive-schedule parallelism:
     the KV range is demand_split into that many pieces, and the partials are
     reduced pairwise along the plan tree.
     """
+    interpret = resolve_interpret(interpret)
     B, H, hd = q.shape
     S = k_cache.shape[1]
     bk = min(block_k, S)

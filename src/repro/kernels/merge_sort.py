@@ -18,7 +18,7 @@ Structure mirrors Kvik's sort, batched level-by-level for a compiled target
      program, ``log2(n/tile)`` launches total.  The kernel is lowered for
      real TPUs: 2-D ``(8, tile//8)`` blocks and the per-block ``la``
      co-rank scalar delivered in SMEM via ``PrefetchScalarGridSpec``
-     (``interpret=True`` remains the tested default).
+     (interpreted off the TPU, see ``kernels.resolve_interpret``).
 
 Stability: keys are packed as ``key << idx_bits | index`` into uint32 —
 equal keys order by original index.  ``idx_bits`` is derived per call as
@@ -43,6 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core import SeqWork, bound_depth, build_plan, even_levels
+from . import resolve_interpret
 from .launch_trace import LaunchRecord, record, trace_launches
 from .radix_sort import (SENTINEL, multi_tile_argsort_packed,  # noqa: F401 —
                          radix_tile_sort,                # SENTINEL re-export
@@ -185,10 +186,11 @@ def _merge_level_kernel(la_ref, a_ref, b_ref, o_ref, *, nb, unpack_mask):
 
 
 def tile_sort(x: jnp.ndarray, *, tile: int = 1024,
-              interpret: bool = True) -> jnp.ndarray:
+              interpret: Optional[bool] = None) -> jnp.ndarray:
     """Sort each tile of a (n,) uint32 array locally with the bitonic
     network (the seed kernel — kept as the radix baseline and fallback).
     n % tile == 0."""
+    interpret = resolve_interpret(interpret)
     n = x.shape[0]
     tile = min(tile, n)
     assert n % tile == 0 and (tile & (tile - 1)) == 0
@@ -313,12 +315,13 @@ def _merge_level(x: jnp.ndarray, *, run: int, tile: int, interpret: bool,
 
 
 def merge_pair(a: jnp.ndarray, b: jnp.ndarray, *, tile: int = 1024,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: Optional[bool] = None) -> jnp.ndarray:
     """Merge two sorted arrays of equal power-of-two length.
 
     Compatibility wrapper: one num_pairs=1 level of the level-batched
     merge-path kernel.
     """
+    interpret = resolve_interpret(interpret)
     n = a.shape[0]
     return _merge_level(jnp.concatenate([a, b]), run=n, tile=min(tile, n),
                         interpret=interpret)
@@ -349,7 +352,8 @@ def _tile_plan(n: int, tile: int):
     return plan, depth, tile
 
 
-def sort_u32(x: jnp.ndarray, *, tile: int = 1024, interpret: bool = True,
+def sort_u32(x: jnp.ndarray, *, tile: int = 1024,
+             interpret: Optional[bool] = None,
              method: str = "radix", total_bits: int = 32,
              digit_bits: int = 4, group: int = 8) -> jnp.ndarray:
     """Stable-ready sort of packed uint32 keys: tile sort, then one launch
@@ -360,6 +364,7 @@ def sort_u32(x: jnp.ndarray, *, tile: int = 1024, interpret: bool = True,
     when the packed width is known, e.g. ``num_key_bits + idx_bits``);
     ``method="bitonic"`` keeps the seed's O(m·log²m) network.
     """
+    interpret = resolve_interpret(interpret)
     n = x.shape[0]
     if n & (n - 1):
         raise ValueError(f"sort_u32 needs a power-of-two input, got n={n} "
@@ -460,7 +465,8 @@ def _argsort_jitted(keys, **kw):
 
 
 def argsort(keys: jnp.ndarray, *, num_key_bits: int = 12, tile: int = 1024,
-            interpret: bool = True, jit: bool = False, method: str = "radix",
+            interpret: Optional[bool] = None, jit: bool = False,
+            method: str = "radix",
             fused: Optional[bool] = None, digit_bits: int = 4,
             group: int = 8, strategy: Optional[str] = None) -> jnp.ndarray:
     """Stable argsort of small-integer keys (expert ids) — MoE dispatch entry.
@@ -489,6 +495,7 @@ def argsort(keys: jnp.ndarray, *, num_key_bits: int = 12, tile: int = 1024,
     bit-identical.  With ``jit=True`` the whole pipeline runs as one
     compiled program, cached per shape/config.
     """
+    interpret = resolve_interpret(interpret)
     n = keys.shape[0]
     if fused is None:
         fused = method == "radix"

@@ -1,8 +1,7 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False when a real
-TPU backend is present — the kernels are the TPU TARGET; interpret mode
-executes the kernel bodies in Python for correctness validation.
+The kernels resolve ``interpret`` themselves (``kernels.resolve_interpret``):
+compiled on a TPU, interpreted elsewhere for correctness validation.
 """
 
 from __future__ import annotations
@@ -17,28 +16,21 @@ from .flash_decode import flash_decode as _flash_decode
 from .merge_sort import argsort as _argsort
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                     block_k: int = 128):
     return _flash_attention(q, k, v, causal=causal, block_q=block_q,
-                            block_k=block_k,
-                            interpret=_default_interpret())
+                            block_k=block_k)
 
 
 @partial(jax.jit, static_argnames=("block_k",))
 def flash_decode(q, k_cache, v_cache, lengths, *, block_k: int = 512):
-    return _flash_decode(q, k_cache, v_cache, lengths, block_k=block_k,
-                         interpret=_default_interpret())
+    return _flash_decode(q, k_cache, v_cache, lengths, block_k=block_k)
 
 
 @partial(jax.jit, static_argnames=("num_key_bits", "tile"))
 def stable_argsort(keys, *, num_key_bits: int = 12, tile: int = 1024):
-    return _argsort(keys, num_key_bits=num_key_bits, tile=tile,
-                    interpret=_default_interpret())
+    return _argsort(keys, num_key_bits=num_key_bits, tile=tile)
 
 
 __all__ = ["flash_attention", "flash_decode", "stable_argsort"]

@@ -48,12 +48,15 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.plan import digit_passes
+from . import resolve_interpret
 from .launch_trace import record
 
 # the single definition — merge_sort imports it: pad words must compare
@@ -223,11 +226,12 @@ def _block_imap(i):
 
 def radix_tile_sort(x: jnp.ndarray, *, tile: int = 1024, total_bits: int = 32,
                     digit_bits: int = 4, key_shift: int = 0, group: int = 8,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """Sort each tile of a (n,) uint32 array by the ``total_bits`` bits at
     ``key_shift`` — stable, so tie order (bits outside the range) is
     preserved.  Drop-in replacement for the bitonic ``tile_sort``;
     ``ceil(total_bits / digit_bits)`` passes run inside one launch."""
+    interpret = resolve_interpret(interpret)
     n = x.shape[0]
     tile = min(tile, n)
     _check_tile(tile, digit_bits)
@@ -254,7 +258,7 @@ def radix_tile_sort_packed(keys: jnp.ndarray, *, n: int, tile: int,
                            num_key_bits: int, idx_bits: int,
                            digit_bits: int = 4, group: int = 8,
                            unpack: bool = False, passes=None,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: Optional[bool] = None) -> jnp.ndarray:
     """Fused pack + tile sort: raw int32 keys (padded to a multiple of
     ``tile``; pad rows must carry the max key) → per-tile-sorted packed
     uint32 words ``key << idx_bits | global_index``, pad slots as the
@@ -264,6 +268,7 @@ def radix_tile_sort_packed(keys: jnp.ndarray, *, n: int, tile: int,
     :meth:`~repro.core.plan.Plan.sort_schedule` digit-pass tuple and is
     what actually parameterizes the kernel (pass count, digit stride and
     ranked bit-width all come from it; derived locally when absent)."""
+    interpret = resolve_interpret(interpret)
     n_pad = keys.shape[0]
     tile = min(tile, n_pad)
     assert n_pad % tile == 0
@@ -434,7 +439,7 @@ def multi_tile_argsort_packed(keys: jnp.ndarray, *, n: int, tile: int,
                               num_key_bits: int, idx_bits: int,
                               digit_bits: int = 4, group: int = 8,
                               scan_block: int = 256, passes=None,
-                              interpret: bool = True) -> jnp.ndarray:
+                              interpret: Optional[bool] = None) -> jnp.ndarray:
     """Global stable argsort via multi-tile LSD radix — no merge tree.
 
     keys: raw int32, padded to a multiple of ``tile`` with the max key (pad
@@ -445,6 +450,7 @@ def multi_tile_argsort_packed(keys: jnp.ndarray, *, n: int, tile: int,
     the plan's ``sort_schedule(mode="multi_tile")`` digit passes
     (``key_shift`` must equal ``idx_bits``: digits rank the key bits of the
     packed word, above the index bits)."""
+    interpret = resolve_interpret(interpret)
     from .tile_scan import histogram_offsets
 
     n_pad = keys.shape[0]
@@ -480,137 +486,109 @@ def multi_tile_argsort_packed(keys: jnp.ndarray, *, n: int, tile: int,
 
 
 # ---------------------------------------------------------------------------
-# one-launch MoE dispatch: sort + gather fused into a single pallas_call
+# one-launch MoE dispatch: the stable counting sort of expert ids
 # ---------------------------------------------------------------------------
 
-def _moe_dispatch_kernel(a_ref, o_ref, hist_ref, offs_ref, *, radix, d_col):
-    """Two-sweep grid ``(2, nt)`` over the augmented row matrix
-    ``A = [activations | e | p | tok]`` (f32; the expert id rides in column
-    ``d_col``).
+def _moe_dispatch_kernel(e_ref, d_ref, base_ref, *, radix):
+    """Stable counting-sort destinations of the ``(nt, tile)`` expert ids.
 
-    Sweep 0 fills the ``(nt, R)`` histogram scratch.  Step (1, 0) turns it
-    into global digit base offsets (digit-major exclusive scan — the
-    ``histogram_offsets`` arithmetic, inline on scratch since the whole
-    matrix is already in VMEM).  Sweep 1 stably sorts each tile's rows by
-    expert digit (one-hot matmul row permutation — exact: every output row
-    receives exactly one source row) and window-scatters the (tile, digit)
-    segments at their global offsets, exactly like ``_mt_scatter_kernel``
-    but moving whole rows.  One digit pass suffices because ``E ≤ radix``."""
-    s = pl.program_id(0)
-    t = pl.program_id(1)
-    m, C = a_ref.shape
-    av = a_ref[...]
-    e = av[:, d_col].astype(jnp.uint32).reshape(1, m)
-    rank, counts = _rank_and_counts(e, jnp.uint32(0),
-                                    jnp.uint32(radix - 1), radix)
+    ``d[t, i]`` = #(ids with a smaller digit anywhere) + #(same digit in
+    earlier tiles) + #(same digit earlier in tile ``t``) — where row ``i`` of
+    tile ``t`` lands in the expert-sorted order.  The first loop fills the
+    same-digit-in-earlier-tiles prefix of every tile (``base_ref``); the
+    second ranks each tile.  Counts are one-hot matmuls (a triangular ones
+    matrix stands in for a cumsum, which Mosaic does not lower) and stay
+    exact in f32: every value is an integer below 2^24, and the matmuls
+    whose operands are not 0/1 run at full f32 precision."""
+    nt, m = e_ref.shape
+    f32 = jnp.float32
+    exact = jax.lax.Precision.HIGHEST
+    digit = jax.lax.broadcasted_iota(jnp.int32, (radix, m), 0)
 
-    @pl.when(s == 0)
-    def _():
-        hist_ref[pl.ds(t, 1), :] = counts
+    def onehot(t):                                    # (R, tile)
+        return (e_ref[pl.ds(t, 1), :] == digit).astype(f32)
 
-    @pl.when((s == 1) & (t == 0))
-    def _():
-        h = hist_ref[...]                             # (nt, R)
-        flat = h.T.reshape(-1)                        # digit-major
-        excl = jnp.cumsum(flat) - flat
-        offs_ref[...] = excl.reshape(radix, -1).T
+    ones = jnp.ones((1, m), f32)
 
-    @pl.when(s == 1)
-    def _():
-        # stable local sort of the rows: out[r, :] = A[rank⁻¹(r), :]
-        poh = (rank.reshape(m)[:, None] ==
-               jax.lax.broadcasted_iota(jnp.int32, (m, m), 1)
-               ).astype(jnp.float32)
-        rows = jnp.einsum("ir,ic->rc", poh, av,
-                          preferred_element_type=jnp.float32)
-        h = counts.reshape(radix)
-        lstart = jnp.cumsum(h) - h
-        base = offs_ref[pl.ds(t, 1), :].reshape(radix)
-        xx = jnp.concatenate([rows, jnp.zeros((m, C), rows.dtype)])
-        idx = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0).reshape(m)
+    def count(t, run):
+        base_ref[pl.ds(t, 1), :] = run
+        return run + jax.lax.dot_general(             # (1, R) histogram
+            ones, onehot(t), (((1,), (1,)), ((), ())),
+            preferred_element_type=f32)
 
-        def body(d, carry):
-            cnt = jax.lax.dynamic_index_in_dim(h, d, keepdims=False)
-            ls = jax.lax.dynamic_index_in_dim(lstart, d, keepdims=False)
-            gb = jax.lax.dynamic_index_in_dim(base, d, keepdims=False)
-            seg = jax.lax.dynamic_slice(xx, (ls, 0), (m, C))
-            cur = o_ref[pl.ds(gb, m), :]
-            o_ref[pl.ds(gb, m), :] = jnp.where(idx[:, None] < cnt, seg, cur)
-            return carry
+    total = jax.lax.fori_loop(0, nt, count, jnp.zeros((1, radix), f32))
+    below = (jax.lax.broadcasted_iota(jnp.int32, (radix, radix), 0) <
+             jax.lax.broadcasted_iota(jnp.int32, (radix, radix), 1))
+    smaller = jnp.dot(total, below.astype(f32), precision=exact,
+                      preferred_element_type=f32)     # (1, R)
+    upto = (jax.lax.broadcasted_iota(jnp.int32, (m, m), 0) <=
+            jax.lax.broadcasted_iota(jnp.int32, (m, m), 1)).astype(f32)
 
-        jax.lax.fori_loop(0, radix, body, 0)
+    def place(t, carry):
+        oh = onehot(t)
+        seen = jnp.dot(oh, upto, preferred_element_type=f32)   # (R, tile)
+        within = jnp.sum(oh * seen, axis=0, keepdims=True) - 1.0
+        base = jnp.dot(base_ref[pl.ds(t, 1), :] + smaller, oh,
+                       precision=exact, preferred_element_type=f32)
+        d_ref[pl.ds(t, 1), :] = (within + base).astype(jnp.int32)
+        return carry
+
+    jax.lax.fori_loop(0, nt, place, 0)
 
 
-def _moe_dispatch_impl(a, *, nt, tile, radix, d_col, interpret):
-    from jax.experimental.pallas import tpu as pltpu
-    n_pad, C = a.shape
-    kernel = functools.partial(_moe_dispatch_kernel, radix=radix, d_col=d_col)
-    record("moe_dispatch", (2, nt), [(tile, C), (n_pad + tile, C)])
-    out = pl.pallas_call(
-        kernel,
-        grid=(2, nt),
-        in_specs=[pl.BlockSpec((tile, C), lambda s, t: (t, 0))],
-        out_specs=pl.BlockSpec((n_pad + tile, C), lambda s, t: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad + tile, C), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((nt, radix), jnp.int32),
-                        pltpu.VMEM((nt, radix), jnp.int32)],
+def _moe_dispatch_impl(e, *, radix, interpret):
+    nt, tile = e.shape
+    record("moe_dispatch", (1,), [(nt, tile)])
+    return pl.pallas_call(
+        functools.partial(_moe_dispatch_kernel, radix=radix),
+        out_shape=jax.ShapeDtypeStruct((nt, tile), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((nt, radix), jnp.float32)],
         interpret=interpret,
-    )(a)
-    return out
+    )(e)
 
 
-_MOE_DISPATCH_STATICS = ("nt", "tile", "radix", "d_col", "interpret")
 _moe_dispatch_jitted = functools.partial(
-    jax.jit, static_argnames=_MOE_DISPATCH_STATICS)(_moe_dispatch_impl)
+    jax.jit, static_argnames=("radix", "interpret"))(_moe_dispatch_impl)
 
 
 def moe_dispatch_sort(x: jnp.ndarray, experts: jnp.ndarray,
                       probs: jnp.ndarray, *, num_experts: int,
-                      tile: int = 512, interpret: bool = True,
+                      tile: int = 512, interpret: Optional[bool] = None,
                       jit: bool = True):
-    """One-``pallas_call`` MoE routing: stable sort of the (T·K,) expert
-    assignments WITH the activation rows carried along — the
-    ``xf[sorted_tok]`` gather of the old pipeline happens inside the final
-    scatter, so dispatch is a single kernel launch at any T.
+    """One-``pallas_call`` MoE routing: the stable sort of the (T·K,)
+    expert assignments by expert id is a single kernel launch at any T,
+    followed by the permutation's row gathers.
 
     x: (T, D) activations; experts/probs: (T, K) from ``route_topk``.
     Returns ``(xd (T·K, D), sorted_e, sorted_tok, sorted_p)`` — bit-identical
-    to the argsort + gather path (f32 row moves are exact: one-hot
-    permutations place each value once; ids/positions are < 2^24).
-    Requires ``num_experts ≤ 256`` (one ≤ 9-bit digit pass; the sentinel
-    digit ``E`` marks pad rows, which sort to the tail and are sliced off).
+    to the stable argsort + gather path (a permutation moves every value
+    unchanged).  The kernel holds only the ids and their destinations, so
+    its VMEM footprint does not grow with ``D``.  Requires
+    ``num_experts ≤ 256`` (one ≤ 9-bit digit; the sentinel digit ``E``
+    marks pad rows, which sort to the tail and are sliced off).
     """
-    T, D = x.shape
+    interpret = resolve_interpret(interpret)
+    T, _ = x.shape
     K = experts.shape[-1]
     E = num_experts
     if E > 256:
         raise ValueError(f"one-launch dispatch needs num_experts ≤ 256, "
                          f"got {E} (fall back to argsort + gather)")
     n = T * K
-    bits = max(1, math.ceil(math.log2(E + 1)))    # digit E = pad sentinel
-    radix = 1 << bits
+    radix = 1 << max(1, math.ceil(math.log2(E + 1)))   # digit E = pad
     tile = min(tile, 1 << max(1, math.ceil(math.log2(max(2, n)))))
     n_pad = -(-n // tile) * tile
 
-    xr = jnp.repeat(x.astype(jnp.float32), K, axis=0)       # (T·K, D)
-    cols = [xr,
-            experts.reshape(n, 1).astype(jnp.float32),
-            probs.reshape(n, 1).astype(jnp.float32),
-            jnp.repeat(jnp.arange(T, dtype=jnp.float32), K).reshape(n, 1)]
-    a = jnp.concatenate(cols, axis=1)
-    if n_pad != n:
-        pad = jnp.zeros((n_pad - n, D + 3), jnp.float32)
-        pad = pad.at[:, D].set(float(E))                    # sentinel digit
-        a = jnp.concatenate([a, pad])
-
+    flat_e = experts.reshape(n).astype(jnp.int32)
+    e = jnp.concatenate([flat_e, jnp.full((n_pad - n,), E, jnp.int32)])
     fn = _moe_dispatch_jitted if jit else _moe_dispatch_impl
-    out = fn(a, nt=n_pad // tile, tile=tile, radix=radix, d_col=D,
-             interpret=interpret)[:n]
-    xd = out[:, :D].astype(x.dtype)
-    sorted_e = out[:, D].astype(jnp.int32)
-    sorted_p = out[:, D + 1].astype(probs.dtype)
-    sorted_tok = out[:, D + 2].astype(jnp.int32)
-    return xd, sorted_e, sorted_tok, sorted_p
+    dest = fn(e.reshape(n_pad // tile, tile), radix=radix,
+              interpret=interpret).reshape(n_pad)[:n]
+    order = jnp.zeros((n,), jnp.int32).at[dest].set(
+        jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+    sorted_tok = order // K
+    return (x[sorted_tok], flat_e[order], sorted_tok,
+            probs.reshape(n)[order])
 
 
 __all__ = ["radix_tile_sort", "radix_tile_sort_packed",

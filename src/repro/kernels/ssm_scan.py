@@ -8,9 +8,9 @@ affine maps, and affine maps form a monoid::
 so the whole recurrence is ONE associative scan — the paper's
 "sequence of parallel operations" shape.  On a launch-per-node tree that
 scan costs ``log n`` launches; here it reuses the ``tile_scan`` carry
-pattern (tile-local ``lax.associative_scan`` + a cross-tile carry pytree in
-VMEM scratch, the same machinery ``histogram_offsets`` uses), so the launch
-count is 1 regardless of sequence length.  Equivalence guarantee: for any
+pattern (a block-local fold + a cross-block carry pytree in VMEM scratch,
+the same machinery ``histogram_offsets`` uses), so the launch count is 1
+regardless of sequence length.  Equivalence guarantee: for any
 monoid the output equals ``jax.lax.associative_scan(combine, xs)`` seeded
 with ``carry0`` — pinned by tests/test_ssm_scan.py and the
 ``BENCH_scan_ssm.json`` equivalence rows.
@@ -26,8 +26,8 @@ Two monoids ship here (see src/repro/models/DESIGN.md for derivations):
   ``X ↦ exp(la)·X + exp(m)·(Ĉ, n̂)`` on the matrix memory; the combine
   max-rebases ``m`` so nothing ever overflows (unit uses ``LOG_ZERO``,
   not −inf: ``-inf − -inf = nan`` inside ``exp`` would poison the unit).
-  Matrix leaves with different shapes → ``tree_scan`` (whole-feature
-  blocks, only the chunk axis is tiled).
+  Matrix leaves with different shapes → ``tree_scan``, one scan per head,
+  the ``(dh, dh)`` memory tiled by rows.
 
 The public wrappers are jit-cached on shape so the serving hot loop never
 retraces; ``*_ref`` twins (pure ``lax.scan`` / ``lax.associative_scan``)
@@ -36,15 +36,18 @@ are the benchmark baselines and the test oracles.
 
 from __future__ import annotations
 
-from functools import partial
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from . import resolve_interpret
 from .tile_scan import batched_scan, tree_scan
 
 LOG_ZERO = -1e30   # the repo-wide "log of zero" that survives exp/arith
+# rows of the (dh, dh) mLSTM memory per grid step: with 8 chunks per block a
+# step holds 2 MiB of it at dh=1024, well inside the 16 MiB VMEM limit
+MLSTM_ROW_BLOCK = 64
 
 
 # ---------------------------------------------------------------------------
@@ -62,6 +65,11 @@ def affine_combine(a: Tuple[jnp.ndarray, jnp.ndarray],
 AFFINE_UNITS = (1.0, 0.0)
 
 
+def _scale(s: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """``s`` broadcast over the trailing feature axes of ``x``."""
+    return s.reshape(s.shape + (1,) * (x.ndim - s.ndim)) * x
+
+
 def logspace_affine_combine(a, b):
     """Stabilized log-space affine monoid for the mLSTM matrix memory.
 
@@ -69,16 +77,18 @@ def logspace_affine_combine(a, b):
     with ``(C, n)`` stored at scale ``exp(m)`` — i.e. the true update is
     ``exp(m)·C``.  The combine rebases both terms onto
     ``m' = max(m1 + la2, m2)``, so every exponent is ≤ 0: no overflow for
-    any gate magnitudes.  ``la`` never enters an exp by itself.
+    any gate magnitudes.  ``la`` never enters an exp by itself.  The scales
+    broadcast over the trailing axes of ``C``/``n``, so the same combine
+    takes the model's ``(B, H)`` heads and the kernel's per-head ``(1, 1)``
+    rows.
     """
     la1, m1, C1, n1 = a
     la2, m2, C2, n2 = b
     m = jnp.maximum(m1 + la2, m2)
     s1 = jnp.exp(m1 + la2 - m)
     s2 = jnp.exp(m2 - m)
-    C = s1[..., None, None] * C1 + s2[..., None, None] * C2
-    n = s1[..., None] * n1 + s2[..., None] * n2
-    return (la1 + la2, m, C, n)
+    return (la1 + la2, m, _scale(s1, C1) + _scale(s2, C2),
+            _scale(s1, n1) + _scale(s2, n2))
 
 
 LOGSPACE_UNITS = (0.0, LOG_ZERO, 0.0, 0.0)
@@ -100,12 +110,13 @@ def _cached(key, build) -> Callable:
 
 def mamba_assoc_scan(dA: jnp.ndarray, dBx: jnp.ndarray, h0: jnp.ndarray, *,
                      block: int = 64, fblock: int = 2048,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: Optional[bool] = None) -> jnp.ndarray:
     """Chunked selective scan: ``h_t = dA_t · h_{t-1} + dBx_t`` over axis 1.
 
     dA, dBx: (B, c, Di, N) fp32;  h0: (B, Di, N) → states (B, c, Di, N),
     ONE pallas launch for any ``c``.
     """
+    interpret = resolve_interpret(interpret)
     key = ("mamba", dA.shape, str(dA.dtype), block, fblock, interpret)
 
     def build():
@@ -143,26 +154,44 @@ def mamba_seq_scan_ref(dA: jnp.ndarray, dBx: jnp.ndarray,
 
 
 def mlstm_carry_scan(la: jnp.ndarray, mS: jnp.ndarray, Chat: jnp.ndarray,
-                     nhat: jnp.ndarray, carry0, *, block: int = 32,
-                     interpret: bool = True):
+                     nhat: jnp.ndarray, carry0, *, block: int = 8,
+                     interpret: Optional[bool] = None):
     """Exclusive monoid scan over the chunk axis → state ENTERING each chunk.
 
     la, mS: (nc, B, H);  Chat: (nc, B, H, dh, dh);  nhat: (nc, B, H, dh) —
     per-chunk summaries.  ``carry0 = (m0, C0, n0)`` is the state entering
     chunk 0.  Returns (la_ent, m_ent, C_ent, n_ent) with
     ``ent[k] = carry0 ∘ e_0 ∘ … ∘ e_{k-1}`` — one pallas launch.
+
+    Each of the ``B·H`` heads is its own scan, and the ``(dh, dh)`` memory
+    is tiled by ``MLSTM_ROW_BLOCK`` rows (the combine rescales it row by
+    row): one whole head's memory is 4 MiB per chunk at dh=1024.
     """
+    interpret = resolve_interpret(interpret)
     m0, C0, n0 = carry0
+    nc, B, H, dh = nhat.shape
     key = ("mlstm", la.shape, Chat.shape, str(la.dtype), block, interpret)
 
     def build():
+        def rows(t, r, c):     # (nc, B, H, ...) → (B·H, nc, r, c)
+            return t.reshape(nc, B * H, r, c).swapaxes(0, 1)
+
+        def back(t):           # (B·H, nc, r, c) → (nc, B, H, ...)
+            return t.swapaxes(0, 1).reshape((nc, B, H) + t.shape[2:])
+
         def run(la, mS, Chat, nhat, m0, C0, n0):
-            return tree_scan(
-                (la, mS, Chat, nhat), combine=logspace_affine_combine,
-                units=LOGSPACE_UNITS,
-                carry0=(jnp.zeros_like(m0), m0, C0, n0),
-                inclusive=False, block=block, interpret=interpret,
-                kind="ssm_scan")
+            ent = tree_scan(
+                (rows(la, 1, 1), rows(mS, 1, 1), rows(Chat, dh, dh),
+                 rows(nhat, 1, dh)),
+                combine=logspace_affine_combine, units=LOGSPACE_UNITS,
+                carry0=(jnp.zeros((B * H, 1, 1), la.dtype),
+                        m0.reshape(B * H, 1, 1), C0.reshape(B * H, dh, dh),
+                        n0.reshape(B * H, 1, dh)),
+                inclusive=False, block=block, rblock=MLSTM_ROW_BLOCK,
+                interpret=interpret, kind="ssm_scan")
+            la_e, m_e, C_e, n_e = (back(t) for t in ent)
+            return (la_e.reshape(nc, B, H), m_e.reshape(nc, B, H),
+                    C_e, n_e.reshape(nc, B, H, dh))
         return run
 
     return _cached(key, build)(la, mS, Chat, nhat, m0, C0, n0)

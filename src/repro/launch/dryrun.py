@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture × shape × mesh).
 
 For each cell this lowers the real step function (train_step / prefill_step /
@@ -24,6 +21,7 @@ Usage:
 import argparse
 import gc
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -127,8 +125,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     hlo = compiled.as_text()
     custom = analyze_hlo(hlo)
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):     # per-computation list on some jax
-        ca = ca[0] if ca else {}
     # persist compressed HLO so the analyzer can be iterated w/o recompiles
     try:
         import zstandard as zstd
@@ -163,6 +159,9 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main() -> None:
+    # 512 host devices stand in for the production mesh; set before the
+    # first JAX call initializes the CPU backend
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -12,6 +12,18 @@ jax device state.  Shapes fixed by the assignment:
 from __future__ import annotations
 
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes, devices=None):
+    """Mesh with Auto axis types: shardings propagate as under
+    ``with mesh:`` (``jax.make_mesh`` defaults to Explicit axes, under which
+    an unannotated scatter inside the model raises ``ShardingTypeError``)."""
+    types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(shape, axes, axis_types=types)
+    return Mesh(np.array(devices).reshape(shape), axes, axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,17 +34,15 @@ def make_production_mesh(*, multi_pod: bool = False):
         n *= s
     devices = jax.devices()
     if len(devices) != n:   # dry-run: 512 forced host devices, use first n
-        import numpy as np
-        from jax.sharding import Mesh
-        return Mesh(np.array(devices[:n]).reshape(shape), axes)
-    return jax.make_mesh(shape, axes)
+        return _auto_mesh(shape, axes, devices[:n])
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 2, pod: int = 0):
     """Small mesh over host (CPU) devices for tests; same axis names."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 __all__ = ["make_production_mesh", "make_host_mesh"]

@@ -93,8 +93,11 @@ class Model:
             return [layer_init(kk[i], cfg, s)
                     for i, s in enumerate(self.period_specs)]
 
-        reps = [one_period(next(ks)) for _ in range(self.repeats)]
-        params["stage"] = jax.tree.map(lambda *xs: jnp.stack(xs), *reps)
+        # vmapped over the repeats: the stack is built directly (no second
+        # per-layer copy), and under jit the period compiles once instead of
+        # unrolling every layer; values equal a per-repeat loop's
+        rep_keys = jnp.stack([next(ks) for _ in range(self.repeats)])
+        params["stage"] = jax.vmap(one_period)(rep_keys)
 
         if cfg.is_encdec:
             encs = [layer_init(next(ks), cfg, self.enc_spec)

@@ -163,15 +163,13 @@ def sort_route(params: Params, cfg: ModelConfig, x: jnp.ndarray,
     Returns ``(xd, sorted_e, sorted_tok, sorted_p, aux)`` with ``xd`` the
     permuted activations (T·K, D).  ``sort_fn(keys) -> order`` must be a
     *stable* argsort — default ``jnp.argsort(stable=True)``; the string
-    ``"pallas"`` routes through the one-launch fused dispatch kernel
+    ``"pallas"`` routes through the one-launch dispatch kernel
     (``kernels.radix_sort.moe_dispatch_sort``): the stable sort by expert
-    id AND the ``xf[sorted_tok]`` activation gather happen inside a single
-    ``pallas_call`` — activation rows ride through the radix scatter as
-    payload, so routing costs one kernel launch at any T (``jit=True``
-    caches the compiled kernel per (T·K, E, D) shape).  Expert counts
-    beyond the kernel's 256-expert digit width fall back to the multi-tile
-    radix ``argsort`` + gather.  Used by ``moe_sort_dispatch`` and
-    ``repro.dist.expert.moe_shard_map``.
+    id is a single ``pallas_call`` at any T, and the activation rows follow
+    by one gather (``jit=True`` caches the compiled kernel per shape).
+    Expert counts beyond the kernel's 256-expert digit width fall back to
+    the multi-tile radix ``argsort`` + gather.  Used by
+    ``moe_sort_dispatch`` and ``repro.dist.expert.moe_shard_map``.
     """
     B, S, D = x.shape
     E, K = cfg.num_experts, cfg.top_k
@@ -182,12 +180,12 @@ def sort_route(params: Params, cfg: ModelConfig, x: jnp.ndarray,
         if E <= 256:
             from ..kernels.radix_sort import moe_dispatch_sort
             xd, sorted_e, sorted_tok, sorted_p = moe_dispatch_sort(
-                xf, experts, probs, num_experts=E, interpret=True, jit=True)
+                xf, experts, probs, num_experts=E, jit=True)
             return xd, sorted_e, sorted_tok, sorted_p, aux
         from ..kernels.merge_sort import argsort as kernel_argsort
         bits = max(1, math.ceil(math.log2(max(2, E))))
         sort_fn = functools.partial(kernel_argsort, num_key_bits=bits,
-                                    interpret=True, jit=True)
+                                    jit=True)
 
     flat_e = experts.reshape(T * K)
     flat_p = probs.reshape(T * K)

@@ -168,6 +168,7 @@ class EngineTelemetry:
     prefill_s_per_token: float = 0.0
     pages_per_request: float = 0.0
     ticks: int = 0
+    prefill_blocks: int = 0
     decode_steps: int = 0
     useful_decoded: int = 0
     admissions: int = 0
@@ -209,6 +210,7 @@ class EngineTelemetry:
 
     def observe_prefill(self, blocks: int, tokens: int,
                         seconds: float) -> None:
+        self.prefill_blocks += blocks
         if blocks:
             self.prefill_s_per_block = self._mix("prefill_s_per_block",
                                                  seconds / blocks)
@@ -240,6 +242,7 @@ class EngineTelemetry:
             "prefill_s_per_token": self.prefill_s_per_token,
             "pages_per_request": self.pages_per_request,
             "ticks": self.ticks,
+            "prefill_blocks": self.prefill_blocks,
             "decode_steps": self.decode_steps,
             "useful_decoded": self.useful_decoded,
             "admissions": self.admissions,
@@ -459,6 +462,8 @@ class ContinuousEngine:
             c: Cap(WorkRange(0, 1 << 30), n + 1)
             for c, n in (cfg.class_caps or {}).items()}
         self.cache = model.init_cache(B, cfg.max_seq)
+        # in place (the batch cache is donated), one compile for every lane
+        self._insert = jax.jit(cache_slot_insert, donate_argnums=0)
         self.lengths = jnp.zeros((B,), jnp.int32)
         self.tokens = jnp.zeros((B,), jnp.int32)
         self.finished = jnp.ones((B,), bool)      # empty lanes are finished
@@ -678,7 +683,7 @@ class ContinuousEngine:
             return
         slot = free[0]
         req = job.req
-        self.cache = cache_slot_insert(self.cache, job.cache, slot)
+        self.cache = self._insert(self.cache, job.cache, slot)
         first = int(np.asarray(
             jnp.argmax(logits[0, :self.model.cfg.vocab_size])))
         req.t_first = time.perf_counter()

@@ -21,10 +21,11 @@ import numpy as np
 
 from ..configs.base import ModelConfig
 from ..data.pipeline import DataConfig, DataPipeline, host_batch_to_device
+from ..dist.sharding import current_ctx
 from ..models.model import Model
 from ..optim.adamw import AdamWConfig, init_state
 from .checkpoint import CheckpointManager, config_fingerprint
-from .step import TrainState, make_train_step
+from .step import TrainState, make_train_step, train_state_shardings
 from .straggler import AdaptiveRebalancer, StragglerDetector, TelemetryBuffer
 
 
@@ -51,11 +52,9 @@ class Trainer:
         self.loop_cfg = loop_cfg
         self.pipeline = DataPipeline(data_cfg)
         self.batch_shardings = batch_shardings
-        self.step_fn = jax.jit(
-            step_fn or make_train_step(
-                model, opt_cfg,
-                num_microbatches=loop_cfg.num_microbatches),
-            donate_argnums=0)
+        self._step = step_fn or make_train_step(
+            model, opt_cfg, num_microbatches=loop_cfg.num_microbatches)
+        self.step_fn = jax.jit(self._step, donate_argnums=0)
         fp = config_fingerprint({
             "model": dataclasses.asdict(model.cfg),
             "opt": dataclasses.asdict(opt_cfg)})
@@ -76,10 +75,23 @@ class Trainer:
         signal.signal(signal.SIGTERM, handler)
         signal.signal(signal.SIGINT, handler)
 
-    def init_or_restore(self) -> TrainState:
+    def _fresh_state(self) -> TrainState:
         params = self.model.init(jax.random.PRNGKey(0))
-        state = TrainState(params=params,
-                           opt=init_state(self.opt_cfg, params))
+        return TrainState(params=params, opt=init_state(self.opt_cfg, params))
+
+    def init_or_restore(self) -> TrainState:
+        ctx = current_ctx()
+        if ctx is None:
+            state = self._fresh_state()
+        else:
+            # made in place on the mesh with the rule table's shardings (no
+            # device ever holds the whole state), and kept in them by every
+            # step, so the step compiles once
+            shardings = train_state_shardings(self.model.cfg, self.model,
+                                              self.opt_cfg, ctx.mesh)
+            state = jax.jit(self._fresh_state, out_shardings=shardings)()
+            self.step_fn = jax.jit(self._step, donate_argnums=0,
+                                   out_shardings=(shardings, None))
         latest = self.ckpt.latest_step()
         if latest is not None:
             abstract = jax.tree.map(

@@ -31,11 +31,8 @@ def test_scan_trip_count_multiplied():
     r = analyze_hlo(txt)
     ideal = 8 * 2 * 128 ** 3
     assert 0.95 * ideal < r["flops_per_chip"] < 1.1 * ideal
-    # XLA's own counter reports ~1/8 of that (the undercount we fix);
-    # cost_analysis() returns a per-computation list on some jax versions
+    # XLA's own counter reports ~1/8 of that (the undercount we fix)
     ca = jax.jit(f).lower(x, w).compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
     assert ca["flops"] < 0.2 * r["flops_per_chip"]
 
 

@@ -542,3 +542,13 @@ def test_moe_dispatch_sort_single_launch_and_exact():
     with pytest.raises(ValueError, match="256"):
         moe_dispatch_sort(jnp.asarray(x), jnp.asarray(e), jnp.asarray(p),
                           num_experts=300)
+
+
+def test_interpret_resolves_from_backend():
+    """One backend-derived default: kernels interpret on the CPU and compile
+    on a TPU; an explicit flag wins."""
+    from repro.kernels import resolve_interpret
+    assert jax.default_backend() == "cpu"
+    assert resolve_interpret(None) is True
+    assert resolve_interpret(False) is False
+    assert resolve_interpret(True) is True

@@ -126,3 +126,27 @@ def test_sharded_train_equals_unsharded():
                     jax.tree.leaves(sh_state.params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
                                    rtol=2e-4)
+
+
+@pytest.mark.parametrize("shape", [dict(data=1, model=1),
+                                   dict(data=1, model=1, pod=1)])
+def test_launch_meshes_scatter_under_mesh_context(shape):
+    """Launch meshes carry Auto axes: a batch dim constrained over 'data'
+    then scattered (the MoE combine's pattern) resolves its sharding under
+    ``mesh_context`` instead of raising ``ShardingTypeError``."""
+    from jax.sharding import AxisType
+    from repro.dist.sharding import constrain
+
+    mesh = make_host_mesh(**shape)
+    assert set(mesh.axis_types) == {AxisType.Auto}
+
+    def combine(y, idx):
+        y = constrain(y, P("data", None))
+        return jnp.zeros((8, 4), y.dtype).at[idx].add(y)
+
+    y = jnp.arange(64.0).reshape(16, 4)
+    idx = jnp.arange(16) % 8
+    with mesh_context(mesh):
+        out = jax.jit(combine)(y, idx)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(combine(y, idx)))

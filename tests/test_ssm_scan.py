@@ -138,12 +138,12 @@ def test_single_launch_any_length(L):
 
 
 def test_tree_scan_single_launch():
-    la = jnp.zeros((20, 1, 1))
+    la = jnp.zeros((3, 20, 1, 1))
     with trace_launches() as tr:
-        tree_scan((la, la - 5.0,
-                   jnp.ones((20, 1, 1, 2, 2)), jnp.ones((20, 1, 1, 2))),
+        tree_scan((la, la - 5.0, jnp.ones((3, 20, 16, 2)),
+                   jnp.ones((3, 20, 1, 2))),
                   combine=logspace_affine_combine, units=LOGSPACE_UNITS,
-                  inclusive=False, block=8, kind="ssm_scan")
+                  inclusive=False, block=8, rblock=8, kind="ssm_scan")
     assert [r.kind for r in tr] == ["ssm_scan"]
 
 
