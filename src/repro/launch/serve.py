@@ -102,13 +102,22 @@ def main() -> None:
                          prompt_len=args.prompt_len, seed=args.seed)
     run = serve(cfg, reqs, max_batch=args.max_batch, max_seq=args.max_seq,
                 seed=args.seed, eos_id=args.eos_id)
-    for r in sorted(run.served, key=lambda r: r.rid):
-        print(f"[launch.serve] req {r.rid}: prompt {len(r.prompt)}, "
-              f"{len(r.result)} tokens, eos={r.stats.all_finished}")
+    eos = {r.rid: r.stats.all_finished for r in run.served}
+    for rec in sorted(run.engine.telemetry.requests, key=lambda r: r.rid):
+        print(f"[launch.serve] req {rec.rid} ({rec.slo}): prompt "
+              f"{rec.prompt_len}, {rec.tokens} tokens, eos={eos[rec.rid]}; "
+              f"queued {1e3 * (rec.t_admit - rec.t_submit):.1f} ms, "
+              f"prefill {rec.prefill_steps} steps / {rec.prefill_blocks} "
+              f"blocks, first token {1e3 * (rec.t_first - rec.t_admit):.1f}"
+              f" ms after admission")
+    tele = run.engine.telemetry
+    print("[launch.serve] host ms by phase (count): " + ", ".join(
+        f"{p} {1e3 * s:.1f} ({tele.phase_n[p]})"
+        for p, s in sorted(tele.phase_s.items())))
     tokens = sum(len(r.result) for r in run.served)
     print(f"[launch.serve] served {len(run.served)}/{args.requests} "
           f"requests, {tokens} tokens in {run.wall_s:.3f}s; "
-          f"telemetry {run.engine.telemetry.snapshot()}")
+          f"telemetry {tele.snapshot()}")
 
 
 if __name__ == "__main__":

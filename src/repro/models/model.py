@@ -168,15 +168,18 @@ class Model:
         return x, aux, ys
 
     def _embed_in(self, params, tokens):
-        return embed(params["embed"], tokens).astype(self.cfg.dtype())
+        with jax.named_scope("embed"):
+            return embed(params["embed"], tokens).astype(self.cfg.dtype())
 
     def _logits_head(self, params, x):
         cfg = self.cfg
         table = params["embed" if cfg.tie_embeddings else "head"]["table"]
-        logits = jnp.einsum("...d,vd->...v", x, table).astype(jnp.float32)
-        if cfg.vocab_padding:
-            neg = jnp.full((cfg.vocab_padding,), -1e30, jnp.float32)
-            logits = logits.at[..., cfg.vocab_size:].set(neg)
+        with jax.named_scope("head"):
+            logits = jnp.einsum("...d,vd->...v", x, table).astype(
+                jnp.float32)
+            if cfg.vocab_padding:
+                neg = jnp.full((cfg.vocab_padding,), -1e30, jnp.float32)
+                logits = logits.at[..., cfg.vocab_size:].set(neg)
         return logits
 
     # ----------------------------------------------------------------- train

@@ -224,17 +224,21 @@ def layer_decode(cfg: ModelConfig, spec: LayerSpec, lp: Params,
         # Mask-select (not scatter): a scatter onto the seq-sharded cache
         # makes GSPMD replicate the whole buffer; the select is local per
         # shard and costs the same read/write the attention pass pays anyway.
-        q, k_new, v_new = gqa_project_qkv(lp["mixer"], cfg, h,
-                                          positions[:, None])
-        S_max = cache["k"].shape[1]
-        at = (jnp.arange(S_max)[None, :] ==
-              lengths[:, None])[:, :, None, None]      # (B,S,1,1)
-        new_cache["k"] = jnp.where(at, k_new[:, 0][:, None], cache["k"])
-        new_cache["v"] = jnp.where(at, v_new[:, 0][:, None], cache["v"])
-        o = decode_attention(q[:, 0], new_cache["k"], new_cache["v"],
-                             lengths + 1)
-        y = jnp.einsum("be,ed->bd", o.reshape(B, -1),
-                       lp["mixer"]["wo"])[:, None]
+        with jax.named_scope("attn.qkv"):
+            q, k_new, v_new = gqa_project_qkv(lp["mixer"], cfg, h,
+                                              positions[:, None])
+        with jax.named_scope("attn.kv_write"):
+            S_max = cache["k"].shape[1]
+            at = (jnp.arange(S_max)[None, :] ==
+                  lengths[:, None])[:, :, None, None]      # (B,S,1,1)
+            new_cache["k"] = jnp.where(at, k_new[:, 0][:, None], cache["k"])
+            new_cache["v"] = jnp.where(at, v_new[:, 0][:, None], cache["v"])
+        with jax.named_scope("attn.attend"):
+            o = decode_attention(q[:, 0], new_cache["k"], new_cache["v"],
+                                 lengths + 1)
+        with jax.named_scope("attn.out"):
+            y = jnp.einsum("be,ed->bd", o.reshape(B, -1),
+                           lp["mixer"]["wo"])[:, None]
     elif spec.kind == "mla":
         y, new_latent = mla_decode(lp["mixer"], cfg, h, cache["latent"],
                                    positions, lengths)
@@ -264,12 +268,13 @@ def layer_decode(cfg: ModelConfig, spec: LayerSpec, lp: Params,
 
     if spec.has_ffn:
         h2 = norm(lp["ln2"], x, cfg.norm_eps)
-        if spec.is_moe:
-            y, _ = moe_apply(lp["moe"], cfg, h2, strategy=moe_strategy,
-                             group_size=min(256, x.shape[0]))
-            x = x + y
-        else:
-            x = x + _ffn_apply(cfg, lp["ffn"], h2)
+        with jax.named_scope("mlp"):
+            if spec.is_moe:
+                y, _ = moe_apply(lp["moe"], cfg, h2, strategy=moe_strategy,
+                                 group_size=min(256, x.shape[0]))
+            else:
+                y = _ffn_apply(cfg, lp["ffn"], h2)
+        x = x + y
     return x, new_cache
 
 
@@ -298,19 +303,27 @@ def layer_prefill_chunk(cfg: ModelConfig, spec: LayerSpec, lp: Params,
     positions = pos0 + jnp.broadcast_to(jnp.arange(c), (B, c))
 
     if spec.kind == "attn":
-        q, k, v = gqa_project_qkv(lp["mixer"], cfg, h, positions)
-        new_k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, pos0, 1)
-        new_v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, pos0, 1)
+        with jax.named_scope("attn.qkv"):
+            q, k, v = gqa_project_qkv(lp["mixer"], cfg, h, positions)
+        with jax.named_scope("attn.kv_write"):
+            new_k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, pos0,
+                                                        1)
+            new_v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, pos0,
+                                                        1)
         new_cache["k"], new_cache["v"] = new_k, new_v
         S_max = new_k.shape[1]
         qc, kc = attn_chunk_sizes(c, S_max)
-        if c <= 256 and S_max <= 1024:
-            o = plain_attention(q, new_k, new_v, causal=True,
-                                q_offset=pos0)
-        else:
-            o = blockwise_attention(q, new_k, new_v, causal=True,
-                                    q_chunk=qc, kv_chunk=kc, q_offset=pos0)
-        y = jnp.einsum("bse,ed->bsd", o.reshape(B, c, -1), lp["mixer"]["wo"])
+        with jax.named_scope("attn.attend"):
+            if c <= 256 and S_max <= 1024:
+                o = plain_attention(q, new_k, new_v, causal=True,
+                                    q_offset=pos0)
+            else:
+                o = blockwise_attention(q, new_k, new_v, causal=True,
+                                        q_chunk=qc, kv_chunk=kc,
+                                        q_offset=pos0)
+        with jax.named_scope("attn.out"):
+            y = jnp.einsum("bse,ed->bsd", o.reshape(B, c, -1),
+                           lp["mixer"]["wo"])
     elif spec.kind == "mla":
         # absorbed chunk attention against the latent history
         payload = mla_cache_payload(lp["mixer"], cfg, h, positions)
@@ -348,12 +361,13 @@ def layer_prefill_chunk(cfg: ModelConfig, spec: LayerSpec, lp: Params,
 
     if spec.has_ffn:
         h2 = norm(lp["ln2"], x, cfg.norm_eps)
-        if spec.is_moe:
-            y2, _ = moe_apply(lp["moe"], cfg, h2, strategy=moe_strategy,
-                              group_size=min(256, c))
-            x = x + y2
-        else:
-            x = x + _ffn_apply(cfg, lp["ffn"], h2)
+        with jax.named_scope("mlp"):
+            if spec.is_moe:
+                y2, _ = moe_apply(lp["moe"], cfg, h2, strategy=moe_strategy,
+                                  group_size=min(256, c))
+            else:
+                y2 = _ffn_apply(cfg, lp["ffn"], h2)
+        x = x + y2
     return x, new_cache
 
 

@@ -16,8 +16,7 @@ schedulers that the paper measures; the waste is *counted* and reported.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,9 +42,10 @@ class DecodeStats:
 
 def make_decode_block(model: Model, eos_id: int):
     """Returns jit'd fn(params, tokens, cache, lengths, finished, n) →
-    (tokens, cache, lengths, finished, out_block (B,n), wasted (B,))."""
+    (tokens, cache, lengths, finished, out_block (B,n), wasted (B,)), the
+    program ``decode_block`` (one compile per ``n``)."""
 
-    def block(params, tokens, cache, lengths, finished, *, n: int):
+    def decode_block(params, tokens, cache, lengths, finished, n: int):
         B = tokens.shape[0]
 
         def body(i, carry):
@@ -64,14 +64,7 @@ def make_decode_block(model: Model, eos_id: int):
         return jax.lax.fori_loop(
             0, n, body, (tokens, cache, lengths, finished, out0, wasted0))
 
-    jits: Dict[int, Callable] = {}
-
-    def dispatch(params, tokens, cache, lengths, finished, n: int):
-        if n not in jits:
-            jits[n] = jax.jit(partial(block, n=n), donate_argnums=2)
-        return jits[n](params, tokens, cache, lengths, finished)
-
-    return dispatch
+    return jax.jit(decode_block, static_argnames="n", donate_argnums=2)
 
 
 def make_decode_tick(model: Model, eos_id: int):
@@ -84,9 +77,10 @@ def make_decode_tick(model: Model, eos_id: int):
     (tokens, cache, lengths, finished, remaining, out (B, n), wasted (B,)).
     Emitted tokens for rows that were already finished (or empty slots) are
     -1; ``lengths`` only advances for live rows, so slot KV stays aligned.
+    The program is named ``tick`` (one compile per ``n``).
     """
 
-    def tick(params, tokens, cache, lengths, finished, remaining, *, n: int):
+    def tick(params, tokens, cache, lengths, finished, remaining, n: int):
         B = tokens.shape[0]
 
         def body(i, carry):
@@ -109,14 +103,7 @@ def make_decode_tick(model: Model, eos_id: int):
             0, n, body,
             (tokens, cache, lengths, finished, remaining, out0, wasted0))
 
-    jits: Dict[int, Callable] = {}
-
-    def dispatch(params, tokens, cache, lengths, finished, remaining, n: int):
-        if n not in jits:
-            jits[n] = jax.jit(partial(tick, n=n), donate_argnums=2)
-        return jits[n](params, tokens, cache, lengths, finished, remaining)
-
-    return dispatch
+    return jax.jit(tick, static_argnames="n", donate_argnums=2)
 
 
 def make_gated_decode_tick(model: Model, eos_id: int, *, tau: float,
@@ -138,11 +125,11 @@ def make_gated_decode_tick(model: Model, eos_id: int, *, tau: float,
 
     Returns fn(params, tokens, cache, lengths, finished, remaining, streak,
     n) → (tokens, cache, lengths, finished, remaining, streak, gated,
-    out (B, n), wasted (B,)).
+    out (B, n), wasted (B,)), the program ``gated_tick``.
     """
 
-    def tick(params, tokens, cache, lengths, finished, remaining, streak,
-             *, n: int):
+    def gated_tick(params, tokens, cache, lengths, finished, remaining,
+                   streak, n: int):
         B = tokens.shape[0]
         V = model.cfg.vocab_size
 
@@ -175,16 +162,7 @@ def make_gated_decode_tick(model: Model, eos_id: int, *, tau: float,
             (tokens, cache, lengths, finished, remaining, streak, gated0,
              out0, wasted0))
 
-    jits: Dict[int, Callable] = {}
-
-    def dispatch(params, tokens, cache, lengths, finished, remaining,
-                 streak, n: int):
-        if n not in jits:
-            jits[n] = jax.jit(partial(tick, n=n), donate_argnums=2)
-        return jits[n](params, tokens, cache, lengths, finished, remaining,
-                       streak)
-
-    return dispatch
+    return jax.jit(gated_tick, static_argnames="n", donate_argnums=2)
 
 
 def decode_until_eos(model: Model, params: Any, first_tokens: jnp.ndarray,

@@ -22,11 +22,14 @@ true per-row lengths.
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
 import math
 import signal
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Set
+from typing import (Any, Deque, Dict, Iterator, List, Optional, Sequence,
+                    Set)
 
 import jax
 import jax.numpy as jnp
@@ -97,8 +100,12 @@ class Request:
     requeues: int = 0             # times re-served from scratch (slot death)
     # wall-clock latency markers (set by the engines)
     t_submit: Optional[float] = None
+    t_admit: Optional[float] = None   # left the queue for its prefill
     t_first: Optional[float] = None   # first token available
     t_done: Optional[float] = None
+    # continuous engine: engine steps and by_blocks blocks its prefill took
+    prefill_steps: int = 0
+    prefill_blocks: int = 0
 
 
 @dataclasses.dataclass
@@ -159,13 +166,35 @@ class EngineConfig:
                 f"exit_patience must be >= 1, got {self.exit_patience}")
 
 
+@dataclasses.dataclass(frozen=True)
+class RequestRecord:
+    """One request as the continuous engine served it, appended to
+    ``EngineTelemetry.requests`` when it retires or is shed.  Times are
+    ``perf_counter`` seconds; a shed request has no admission, first token
+    or tokens."""
+
+    rid: int
+    slo: str
+    t_submit: Optional[float]
+    t_admit: Optional[float]
+    t_first: Optional[float]
+    t_done: Optional[float]
+    prompt_len: int
+    prefill_steps: int            # engine steps its prefill ran in
+    prefill_blocks: int
+    tokens: int
+
+
 @dataclasses.dataclass
 class EngineTelemetry:
-    """Live measurements the admission cap consults (EWMA-smoothed)."""
+    """Live measurements the admission cap consults (EWMA-smoothed), the
+    engine's counters, its host spans (:meth:`span`) and the records of the
+    most recent requests."""
+
+    REQUEST_RECORDS = 1024        # records kept; older ones drop off
 
     decode_s_per_token: float = 0.0
     prefill_s_per_block: float = 0.0
-    prefill_s_per_token: float = 0.0
     pages_per_request: float = 0.0
     ticks: int = 0
     prefill_blocks: int = 0
@@ -175,8 +204,7 @@ class EngineTelemetry:
     prefill_preemptions: int = 0
     deferred_pages: int = 0       # admissions deferred on page exhaustion
     retired: int = 0
-    cap_divides: int = 0
-    cap_finishes: int = 0
+    prefills_completed: int = 0
     cap_live_peak: int = 0
     # SLO / overload accounting
     queue_rejections: int = 0     # submit() refused at max_queue
@@ -187,6 +215,12 @@ class EngineTelemetry:
     policy_swaps: int = 0         # live set_policy() calls
     slot_deaths: int = 0          # decode lanes killed (chaos) and requeued
     early_exits: int = 0          # lanes retired by the entropy gate
+    # host seconds and count of each span() phase, cumulative
+    phase_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    phase_n: Dict[str, int] = dataclasses.field(default_factory=dict)
+    requests: Deque[RequestRecord] = dataclasses.field(
+        default_factory=lambda: collections.deque(
+            maxlen=EngineTelemetry.REQUEST_RECORDS))
     ewma: float = 0.25
     # EWMA fields already seeded by a first observation.  A plain
     # ``old == 0.0`` sentinel misreads a genuine ~0.0 first sample and,
@@ -208,15 +242,11 @@ class EngineTelemetry:
         self.decode_s_per_token = self._mix("decode_s_per_token",
                                             seconds / max(1, useful))
 
-    def observe_prefill(self, blocks: int, tokens: int,
-                        seconds: float) -> None:
+    def observe_prefill(self, blocks: int, seconds: float) -> None:
         self.prefill_blocks += blocks
         if blocks:
             self.prefill_s_per_block = self._mix("prefill_s_per_block",
                                                  seconds / blocks)
-        if tokens:
-            self.prefill_s_per_token = self._mix("prefill_s_per_token",
-                                                 seconds / tokens)
 
     def observe_admission(self, pages: int) -> None:
         self.admissions += 1
@@ -228,18 +258,39 @@ class EngineTelemetry:
             self.shed_by_tenant.get(req.tenant, 0) + 1
         self.shed_by_class[req.slo] = self.shed_by_class.get(req.slo, 0) + 1
 
+    def observe_request(self, req: "Request", tokens: int) -> None:
+        self.requests.append(RequestRecord(
+            rid=req.rid, slo=req.slo, t_submit=req.t_submit,
+            t_admit=req.t_admit, t_first=req.t_first, t_done=req.t_done,
+            prompt_len=len(req.prompt), prefill_steps=req.prefill_steps,
+            prefill_blocks=req.prefill_blocks, tokens=tokens))
+
     def on_cap_event(self, kind: str, live: int) -> None:
-        if kind == "divide":
-            self.cap_divides += 1
-        else:
-            self.cap_finishes += 1
         self.cap_live_peak = max(self.cap_live_peak, live)
 
+    @contextlib.contextmanager
+    def span(self, phase: str, **args: Any) -> Iterator[Any]:
+        """Time one host phase of the engine: a ``serve.<phase>`` profiler
+        span (on the device programs' clock when a trace is on) whose
+        ``perf_counter`` seconds add to ``phase_s[phase]`` and
+        ``phase_n[phase]``.  Yields the span, whose ``set_metadata`` adds
+        arguments known only inside it."""
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(f"serve.{phase}", **args) as ev:
+                yield ev
+        finally:
+            self.phase_s[phase] = (self.phase_s.get(phase, 0.0)
+                                   + time.perf_counter() - t0)
+            self.phase_n[phase] = self.phase_n.get(phase, 0) + 1
+
     def snapshot(self) -> Dict[str, float]:
-        return {
+        """The counters, flat: ``steps`` and ``prefill_calls`` count the
+        ``step`` and ``prefill`` spans, ``phase_s.<phase>`` /
+        ``phase_n.<phase>`` every span's seconds and count."""
+        snap = {
             "decode_s_per_token": self.decode_s_per_token,
             "prefill_s_per_block": self.prefill_s_per_block,
-            "prefill_s_per_token": self.prefill_s_per_token,
             "pages_per_request": self.pages_per_request,
             "ticks": self.ticks,
             "prefill_blocks": self.prefill_blocks,
@@ -249,8 +300,7 @@ class EngineTelemetry:
             "prefill_preemptions": self.prefill_preemptions,
             "deferred_pages": self.deferred_pages,
             "retired": self.retired,
-            "cap_divides": self.cap_divides,
-            "cap_finishes": self.cap_finishes,
+            "prefills_completed": self.prefills_completed,
             "cap_live_peak": self.cap_live_peak,
             "queue_rejections": self.queue_rejections,
             "shed": self.shed,
@@ -258,7 +308,12 @@ class EngineTelemetry:
             "policy_swaps": self.policy_swaps,
             "slot_deaths": self.slot_deaths,
             "early_exits": self.early_exits,
+            "steps": self.phase_n.get("step", 0),
+            "prefill_calls": self.phase_n.get("prefill", 0),
         }
+        snap.update((f"phase_s.{p}", v) for p, v in self.phase_s.items())
+        snap.update((f"phase_n.{p}", v) for p, v in self.phase_n.items())
+        return snap
 
 
 @dataclasses.dataclass
@@ -577,6 +632,7 @@ class ContinuousEngine:
                 r.stats = DecodeStats(all_finished=False)
                 r.t_done = now
                 self.telemetry.observe_shed(r)
+                self.telemetry.observe_request(r, 0)
                 shed.append(r)
             else:
                 kept.append(r)
@@ -592,6 +648,10 @@ class ContinuousEngine:
             return
         if not self._admission.should_be_divided():
             return
+        with self.telemetry.span("admit") as ev:
+            self._admit(ev)
+
+    def _admit(self, ev) -> None:
         req = None
         for qi in self._policy.order(self.queue, time.perf_counter()):
             if self._class_cap_ok(self.queue[qi].slo):
@@ -599,11 +659,13 @@ class ContinuousEngine:
                 break
         if req is None:           # every waiting class is at its cap
             return
+        ev.set_metadata(rid=req.rid)
         pages = self.pages.allocate(req.rid, self._slot_span(req))
         if pages is None:         # page exhaustion → defer admission
             self.telemetry.deferred_pages += 1
             return
         self.queue.remove(req)
+        req.t_admit = time.perf_counter()
         lease, rest = self._admission.divide_at(1)
         self._admission = rest
         class_lease = self._take_class_lease(req.slo)
@@ -659,18 +721,26 @@ class ContinuousEngine:
         if job.done_logits is not None:   # completed earlier, lane-starved
             self._install_job(job, job.done_logits)
             return
+        req, budget = job.req, self._prefill_budget()
         t0 = time.perf_counter()
-        logits, cache, pstats = self.prefiller.run(
-            self.params, job.toks, job.cache, start=job.pos,
-            max_blocks=self._prefill_budget(),
-            row_lengths=[len(job.req.prompt)], gathered=job.gathered)
-        self.telemetry.observe_prefill(pstats.blocks, pstats.tokens,
+        with self.telemetry.span("prefill", rid=req.rid,
+                                 start=job.pos) as ev:
+            logits, cache, pstats = self.prefiller.run(
+                self.params, job.toks, job.cache, start=job.pos,
+                max_blocks=budget, row_lengths=[len(req.prompt)],
+                gathered=job.gathered)
+            ev.set_metadata(stop=job.pos + pstats.tokens,
+                            budget=-1 if budget is None else budget)
+        self.telemetry.observe_prefill(pstats.blocks,
                                        time.perf_counter() - t0)
+        req.prefill_steps += 1
+        req.prefill_blocks += pstats.blocks
         if pstats.preempted:
             job.cache, job.pos, job.gathered = cache, pstats.next_start, \
                 logits
             self.telemetry.prefill_preemptions += 1
             return
+        self.telemetry.prefills_completed += 1
         job.cache = cache
         self._install_job(job, logits)
 
@@ -683,48 +753,57 @@ class ContinuousEngine:
             return
         slot = free[0]
         req = job.req
-        self.cache = self._insert(self.cache, job.cache, slot)
-        first = int(np.asarray(
-            jnp.argmax(logits[0, :self.model.cfg.vocab_size])))
-        req.t_first = time.perf_counter()
-        done = (first == self.cfg.eos_id) or (req.max_new <= 1)
-        L = len(req.prompt)
-        self.lengths = self.lengths.at[slot].set(L)
-        self.tokens = self.tokens.at[slot].set(first)
-        self.finished = self.finished.at[slot].set(done)
-        self.remaining = self.remaining.at[slot].set(req.max_new - 1)
-        self.streak = self.streak.at[slot].set(0)
-        self.slots[slot] = _Slot(req=req, first=first, lease=job.lease,
-                                 class_lease=job.class_lease,
-                                 eos_hit=(first == self.cfg.eos_id))
-        self._job = None
+        with self.telemetry.span("install", rid=req.rid, slot=slot):
+            self.cache = self._insert(self.cache, job.cache, slot)
+            with self.telemetry.span("install.wait", rid=req.rid, slot=slot):
+                first = int(np.asarray(
+                    jnp.argmax(logits[0, :self.model.cfg.vocab_size])))
+            req.t_first = time.perf_counter()
+            done = (first == self.cfg.eos_id) or (req.max_new <= 1)
+            L = len(req.prompt)
+            self.lengths = self.lengths.at[slot].set(L)
+            self.tokens = self.tokens.at[slot].set(first)
+            self.finished = self.finished.at[slot].set(done)
+            self.remaining = self.remaining.at[slot].set(req.max_new - 1)
+            self.streak = self.streak.at[slot].set(0)
+            self.slots[slot] = _Slot(req=req, first=first, lease=job.lease,
+                                     class_lease=job.class_lease,
+                                     eos_hit=(first == self.cfg.eos_id))
+            self._job = None
 
     # --------------------------------------------------------------- decode
     def _decode_tick(self) -> None:
         occupied = [i for i, s in enumerate(self.slots) if s is not None]
         if not occupied:
             return
-        fin = np.asarray(self.finished)
-        if all(fin[i] for i in occupied):
+        with self.telemetry.span("tick.sync"):
+            fin = np.asarray(self.finished)
+        live = sum(not fin[i] for i in occupied)
+        if not live:
             return
+        with self.telemetry.span("tick", live=live):
+            self._run_tick(occupied)
+
+    def _run_tick(self, occupied: List[int]) -> None:
         n = self.cfg.decode_tick
         t0 = time.perf_counter()
+        gated = None
         if self.cfg.exit_entropy is not None:
             (self.tokens, self.cache, self.lengths, self.finished,
              self.remaining, self.streak, gated, out, wasted) = self._tick(
                 self.params, self.tokens, self.cache, self.lengths,
                 self.finished, self.remaining, self.streak, n)
-            gated_np = np.asarray(gated)
         else:
             (self.tokens, self.cache, self.lengths, self.finished,
              self.remaining, out, wasted) = self._tick(
                 self.params, self.tokens, self.cache, self.lengths,
                 self.finished, self.remaining, n)
-            gated_np = None
-        out_np = np.asarray(out)          # blocks until the tick is done
-        self.telemetry.observe_decode(int((out_np >= 0).sum()),
-                                      time.perf_counter() - t0, n)
-        wasted_np = np.asarray(wasted)
+        with self.telemetry.span("tick.wait"):
+            out_np = np.asarray(out)      # blocks until the tick is done
+            seconds = time.perf_counter() - t0
+            wasted_np = np.asarray(wasted)
+            gated_np = None if gated is None else np.asarray(gated)
+        self.telemetry.observe_decode(int((out_np >= 0).sum()), seconds, n)
         for i in occupied:
             s = self.slots[i]
             valid = out_np[i][out_np[i] >= 0]
@@ -738,6 +817,10 @@ class ContinuousEngine:
 
     # --------------------------------------------------------------- retire
     def _retire(self) -> List[Request]:
+        with self.telemetry.span("retire"):
+            return self._retire_finished()
+
+    def _retire_finished(self) -> List[Request]:
         fin = np.asarray(self.finished)
         done: List[Request] = []
         now = time.perf_counter()
@@ -763,6 +846,7 @@ class ContinuousEngine:
                 s.class_lease.on_finish()
             self.slots[i] = None
             self.telemetry.retired += 1
+            self.telemetry.observe_request(r, len(r.result))
             done.append(r)
         return done
 
@@ -789,7 +873,8 @@ class ContinuousEngine:
         self.lengths = self.lengths.at[i].set(0)
         self.streak = self.streak.at[i].set(0)
         r.requeues += 1
-        r.t_first = None
+        r.t_admit = r.t_first = None
+        r.prefill_steps = r.prefill_blocks = 0
         self.queue.insert(0, r)
         self.telemetry.slot_deaths += 1
         return True
@@ -822,6 +907,10 @@ class ContinuousEngine:
         return bool(self.queue) or in_flight
 
     def step(self) -> List[Request]:
+        with self.telemetry.span("step"):
+            return self._step()
+
+    def _step(self) -> List[Request]:
         shed: List[Request] = []
         if not self.preempted:
             shed = self._shed_expired()
@@ -836,4 +925,4 @@ class ContinuousEngine:
 
 
 __all__ = ["Engine", "ContinuousEngine", "EngineConfig", "EngineTelemetry",
-           "Request", "AdmissionSimulator", "QueueFull"]
+           "Request", "RequestRecord", "AdmissionSimulator", "QueueFull"]

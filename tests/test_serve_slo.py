@@ -96,7 +96,7 @@ def test_ewma_first_observation_seeds_directly():
     t2 = EngineTelemetry()
     t2.observe_admission(pages=6)
     assert t2.pages_per_request == 6.0
-    t2.observe_prefill(blocks=0, tokens=0, seconds=0.5)   # no-op: no work
+    t2.observe_prefill(blocks=0, seconds=0.5)   # no-op: no work
     assert t2.prefill_s_per_block == 0.0 and "prefill_s_per_block" \
         not in t2._seeded
 
