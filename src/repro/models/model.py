@@ -441,21 +441,40 @@ class Model:
                 new_prefix.append(lc2)
             new_cache["prefix"] = new_prefix
 
+        # The attention layers' stacked K/V ride in the layer scan's carry
+        # and each layer writes its new row into the stack in place; as
+        # scan xs/ys every step would rewrite and copy the whole caches.
+        # Fixed-size states and read-only ck/cv stay in xs/ys.
         specs = self.period_specs
+        stage = cache["stage"]
+        kv_keys = ("k", "v")
+        kv0 = {pos: {n: stage[pos][n] for n in kv_keys}
+               for pos, spec in enumerate(specs) if spec.kind == "attn"}
+        rest = [{n: a for n, a in lc.items()
+                 if pos not in kv0 or n not in kv_keys}
+                for pos, lc in enumerate(stage)]
 
-        def body(x, xs):
+        def body(carry, xs):
+            x, layer, kv = carry
             stage_lp, stage_cache = xs
+            kv = dict(kv)
             new_slices = []
             for pos, spec in enumerate(specs):
                 x, c2 = layer_decode(cfg, spec, stage_lp[pos], x,
-                                     stage_cache[pos], positions, lengths,
-                                     moe_strategy=self.moe_strategy)
+                                     {**stage_cache[pos], **kv.get(pos, {})},
+                                     positions, lengths,
+                                     moe_strategy=self.moe_strategy,
+                                     layer=layer if pos in kv else None)
+                if pos in kv:
+                    kv[pos] = {n: c2.pop(n) for n in kv_keys}
                 new_slices.append(c2)
-            return x, new_slices
+            return (x, layer + 1, kv), new_slices
 
-        x, new_stage = jax.lax.scan(body, x, (params["stage"],
-                                              cache["stage"]))
-        new_cache["stage"] = new_stage
+        (x, _, kv), new_rest = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.int32), kv0),
+            (params["stage"], rest))
+        new_cache["stage"] = [{**lc, **kv.get(pos, {})}
+                              for pos, lc in enumerate(new_rest)]
         x = self._norm(params["final_norm"], x)
         logits = self._logits_head(params, x)[:, 0]
         return logits, new_cache

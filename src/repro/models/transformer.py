@@ -209,32 +209,64 @@ def layer_apply(cfg: ModelConfig, spec: LayerSpec, lp: Params, x: jnp.ndarray,
 
 # --- decode apply ------------------------------------------------------------
 
+def _layer_slice(cache: jnp.ndarray, layer) -> jnp.ndarray:
+    """The (B, S, KV, hd) cache of one layer: ``cache`` itself, or with
+    ``layer`` its slice of the stacked (R, B, S, KV, hd)."""
+    if layer is None:
+        return cache
+    return jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+
+
+def kv_write(cache: jnp.ndarray, new: jnp.ndarray, lengths: jnp.ndarray,
+             layer=None) -> jnp.ndarray:
+    """``cache`` — (B, S, KV, hd), or with ``layer`` the stacked
+    (R, B, S, KV, hd) at that layer — with each lane's new K or V row
+    ``new`` (B, KV, hd) at position ``lengths[b]``.  A lane at
+    ``lengths == S`` writes nothing.
+
+    Outside a mesh the row is scattered in place: only B rows move.  Under
+    a ``mesh_context`` a scatter onto the seq-sharded cache makes GSPMD
+    replicate the whole buffer, so the write is a mask-select over the
+    layer's slice, local to each shard, put back into the stack."""
+    from ..dist.sharding import current_ctx
+    if current_ctx() is None:
+        idx = (jnp.arange(new.shape[0]), lengths)
+        return cache.at[idx if layer is None else (layer,) + idx].set(
+            new, mode="drop")
+    at = (jnp.arange(cache.shape[-3])[None, :] ==
+          lengths[:, None])[:, :, None, None]              # (B,S,1,1)
+    out = jnp.where(at, new[:, None], _layer_slice(cache, layer))
+    if layer is None:
+        return out
+    return jax.lax.dynamic_update_index_in_dim(cache, out, layer, 0)
+
+
 def layer_decode(cfg: ModelConfig, spec: LayerSpec, lp: Params,
                  x: jnp.ndarray, cache: Dict[str, jnp.ndarray],
                  positions: jnp.ndarray, lengths: jnp.ndarray, *,
-                 moe_strategy: str = "einsum"
+                 moe_strategy: str = "einsum", layer=None
                  ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """x: (B,1,D); cache: per-layer state dict; returns (x, new cache)."""
+    """x: (B,1,D); cache: per-layer state dict; returns (x, new cache).
+
+    With ``layer`` (a traced index), an ``attn`` layer's ``k``/``v`` are the
+    whole stacked caches (R, B, S, KV, hd) and this layer's are at
+    ``layer``; the returned ``k``/``v`` are the stacks again."""
     norm = _norm(cfg)
     B = x.shape[0]
     h = norm(lp["ln1"], x, cfg.norm_eps)
     new_cache = dict(cache)
     if spec.kind == "attn":
-        # write the current token's K/V first — it attends to itself.
-        # Mask-select (not scatter): a scatter onto the seq-sharded cache
-        # makes GSPMD replicate the whole buffer; the select is local per
-        # shard and costs the same read/write the attention pass pays anyway.
+        # write the current token's K/V first — it attends to itself
         with jax.named_scope("attn.qkv"):
             q, k_new, v_new = gqa_project_qkv(lp["mixer"], cfg, h,
                                               positions[:, None])
         with jax.named_scope("attn.kv_write"):
-            S_max = cache["k"].shape[1]
-            at = (jnp.arange(S_max)[None, :] ==
-                  lengths[:, None])[:, :, None, None]      # (B,S,1,1)
-            new_cache["k"] = jnp.where(at, k_new[:, 0][:, None], cache["k"])
-            new_cache["v"] = jnp.where(at, v_new[:, 0][:, None], cache["v"])
+            new_cache["k"] = kv_write(cache["k"], k_new[:, 0], lengths, layer)
+            new_cache["v"] = kv_write(cache["v"], v_new[:, 0], lengths, layer)
         with jax.named_scope("attn.attend"):
-            o = decode_attention(q[:, 0], new_cache["k"], new_cache["v"],
+            o = decode_attention(q[:, 0],
+                                 _layer_slice(new_cache["k"], layer),
+                                 _layer_slice(new_cache["v"], layer),
                                  lengths + 1)
         with jax.named_scope("attn.out"):
             y = jnp.einsum("be,ed->bd", o.reshape(B, -1),
@@ -449,5 +481,5 @@ def layer_cache_shape(cfg: ModelConfig, spec: LayerSpec, batch: int,
 
 __all__ = [
     "LayerSpec", "layer_specs", "stage_layout", "layer_init", "layer_apply",
-    "layer_decode", "layer_cache_shape",
+    "layer_decode", "layer_cache_shape", "kv_write",
 ]

@@ -4,8 +4,8 @@ The model-path Pallas kernels at the widths of the models that use them,
 compiled (not interpreted): a refusal here (an unsupported cast, a
 zero-size or misaligned vector, too much VMEM) is what a chip run would
 hit first.  The full-width minitron-4b decode tick of ``ContinuousEngine``
-must fit one chip's 16 GB.  Nothing runs: these tests say nothing about
-results or times.
+must fit one chip's 16 GB and write its K/V cache in place.  Nothing runs:
+these tests say nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and pytest-xdist workers
@@ -13,6 +13,7 @@ import every test file.
 """
 
 import functools
+import re
 
 import pytest
 
@@ -86,7 +87,7 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_minitron_decode_tick_fits_v5e(one_chip):
+def _compile_minitron_tick(one_chip, batch, max_seq):
     model = Model(get_config("minitron-4b"))
     tick = make_decode_tick(model, eos_id=2)
 
@@ -95,13 +96,56 @@ def test_minitron_decode_tick_fits_v5e(one_chip):
             a.shape, a.dtype, sharding=one_chip), tree)
 
     params = place(model.abstract_params())
-    cache = place(model.abstract_cache(DECODE_BATCH, DECODE_SEQ))
-    lanes = _on(one_chip, (DECODE_BATCH,), dtype=jnp.int32)[0]
-    done = _on(one_chip, (DECODE_BATCH,), dtype=jnp.bool_)[0]
-    compiled = jax.jit(
+    cache = place(model.abstract_cache(batch, max_seq))
+    lanes = _on(one_chip, (batch,), dtype=jnp.int32)[0]
+    done = _on(one_chip, (batch,), dtype=jnp.bool_)[0]
+    return jax.jit(
         lambda p, t, c, l, f, r: tick(p, t, c, l, f, r, 8),
         donate_argnums=2).lower(params, lanes, cache, lanes, done,
                                 lanes).compile()
+
+
+def test_minitron_decode_tick_fits_v5e(one_chip):
+    compiled = _compile_minitron_tick(one_chip, DECODE_BATCH, DECODE_SEQ)
     ma = compiled.memory_analysis()
     need = ma.argument_size_in_bytes + ma.temp_size_in_bytes
     assert need < 15 * GiB, f"decode tick needs {need / GiB:.2f} GiB"
+
+
+# ops that only name or pass on a buffer, and write nothing
+_PLUMBING = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+
+
+def _whole_buffer_writes(hlo, shape):
+    """(name, opcode, op_name) of every op outside a fusion's body whose
+    output holds an array of ``shape``."""
+    fused = set(re.findall(r"fusion\(.*?calls=(%?[\w.\-]+)", hlo))
+    comp, found = None, []
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?(%?[\w.\-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            comp = head.group(1)
+            continue
+        op = re.match(r"\s*(?:ROOT )?(%?[\w.\-]+) = (.*?) ([\w\-]+)\(",
+                      line)
+        if comp in fused or not op or op.group(3) in _PLUMBING:
+            continue
+        if shape in op.group(2):
+            meta = re.search(r'op_name="([^"]*)"', line)
+            found.append((op.group(1), op.group(3),
+                          meta.group(1) if meta else ""))
+    return found
+
+
+def test_minitron_decode_tick_writes_kv_in_place_v5e(one_chip):
+    """At the chat cell's cache (8 lanes x 2048), no op of the tick writes
+    a whole stacked K or V cache but the two in-place scatters of the new
+    rows: no copy, no buffer allocated for the layer scan's output, no
+    rewrite of every position."""
+    batch, max_seq = 8, 2048
+    hlo = _compile_minitron_tick(one_chip, batch, max_seq).as_text()
+    found = _whole_buffer_writes(hlo, f"bf16[32,{batch},{max_seq},8,128]")
+    others = [f for f in found
+              if f[1] != "fusion" or "attn.kv_write/scatter" not in f[2]]
+    assert not others, others
+    assert len(found) == 2, found
